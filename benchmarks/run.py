@@ -92,6 +92,9 @@ def main() -> None:
     names = list(BENCHES) if not args.only else args.only.split(",")
 
     import importlib
+
+    from repro import compile_cache
+    compile_cache.enable()
     failures = 0
     print("name,us_per_call,derived")
     for name in names:

@@ -122,14 +122,16 @@ def pack_int4(q: np.ndarray) -> np.ndarray:
 
 
 def unpack_int4(packed, rows: int):
-    """Inverse of ``pack_int4`` (jnp: used inside jitted dequant)."""
-    p = jnp.asarray(packed).astype(jnp.uint8)
-    lo = (p & 0xF).astype(jnp.int8)
-    hi = ((p >> 4) & 0xF).astype(jnp.int8)
+    """Inverse of ``pack_int4`` (jnp: used inside jitted dequant and the
+    ``quantized_matmul`` kernel).  The nibbles are shifted and masked in
+    int32: Mosaic cannot lower shifts on 8-bit integers."""
+    p = jnp.asarray(packed).astype(jnp.uint8).astype(jnp.int32)
+    lo = p & 0xF
+    hi = (p >> 4) & 0xF
     lo = jnp.where(lo > 7, lo - 16, lo)
     hi = jnp.where(hi > 7, hi - 16, hi)
     full = jnp.stack([lo, hi], axis=1).reshape((-1,) + p.shape[1:])
-    return full[:rows]
+    return full[:rows].astype(jnp.int8)
 
 
 # ---------------------------------------------------------------------------

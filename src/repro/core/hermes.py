@@ -28,6 +28,7 @@ from repro.checkpoint.partition import ensure_quantized
 from repro.core.engine import DraftModel, PipeloadEngine, RunStats
 from repro.core.planner import GenPlanEntry, PlanEntry, plan, plan_generate
 from repro.core.profiler import load_profile, profile_model, save_profile
+from repro.kernels.autotune import device_arch
 from repro.models.config import ModelConfig
 
 # planner label for "no quantization: stream shards at the ckpt dtype"
@@ -53,7 +54,10 @@ class Hermes:
     # ---- Layer Profiler ------------------------------------------------
     def profile(self, *, batch: int = 1, seq: int = 128,
                 force: bool = False) -> Dict:
-        cache = self.dir / "profile.json"
+        """Measured per-shard profile, cached in the checkpoint directory
+        under the measuring device's kind (``profile.<arch>.json``): a
+        profile taken on one device never plans a run on another."""
+        cache = self.dir / f"profile.{device_arch()}.json"
         if not force and self._profile is not None:
             return self._profile
         if not force and cache.exists():
@@ -83,7 +87,7 @@ class Hermes:
     def quantized(self, quant: Optional[str]) -> "Hermes":
         """Hermes over the ``quant`` variant of this checkpoint.  The
         sibling directory ``<dir>-<quant>`` is transcoded once (no model
-        init) and reused — including its own cached profile.json — and
+        init) and reused — including its own cached per-device profile — and
         re-transcoded automatically if the source checkpoint changed
         underneath it (``checkpoint.ensure_quantized``)."""
         if quant in (None, FP_LABEL):

@@ -73,11 +73,16 @@ def check_engine_family(cfg: ModelConfig, where: str = "the PIPELOAD "
             f"{', '.join(ENGINE_FAMILIES)}")
 
 
-def resolve_attn_impl(attn_impl: Optional[str]) -> Optional[str]:
+def resolve_attn_impl(attn_impl: Optional[str],
+                      cfg: Optional[ModelConfig] = None) -> Optional[str]:
     """"auto" -> the autotuned per-device choice when one is installed
     (kernels/autotune.py), else Pallas kernel on TPU and jnp online
     softmax elsewhere (interpret-mode Pallas is a validation tool, not a
-    fast path)."""
+    fast path).  MLA decode always takes the jnp path: its latent K and V
+    heads differ in width, which the Pallas decode kernels do not take —
+    decided here, where callers can see it, not inside a traced fn."""
+    if cfg is not None and cfg.attention == "mla":
+        return None
     if attn_impl == "auto":
         from repro.kernels import ops
         tuned = ops.tuned_paged_impl()
@@ -102,7 +107,7 @@ def build_module_fns(cfg: ModelConfig,
     (moe_router/moe_router_cache/moe_router_decode/moe_combine) for
     MoE-family configs."""
     check_engine_family(cfg)
-    impl = resolve_attn_impl(attn_impl)
+    impl = resolve_attn_impl(attn_impl, cfg)
 
     @jax.jit
     def embed_apply(weights, tokens):

@@ -63,15 +63,11 @@ def default_cache_path() -> Path:
 
 
 def device_arch() -> str:
-    """Stable per-device key: the accelerator kind on real hardware,
-    the JAX backend name otherwise."""
-    try:
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", "") or ""
-        kind = kind.strip().lower().replace(" ", "-")
-        return kind or jax.default_backend()
-    except Exception:  # noqa: BLE001 — no backend: still a usable key
-        return jax.default_backend()
+    """Stable per-device key: the default device's ``device_kind``
+    (``"tpu-v5-lite"`` on a v5e, ``"cpu"`` on the CPU backend).  A
+    process that sees no device raises here rather than keying tuned
+    tiles or profiles under a made-up name."""
+    return jax.devices()[0].device_kind.strip().lower().replace(" ", "-")
 
 
 class AutotuneCache:

@@ -33,7 +33,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_out_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[...] * scale                                  # (1, dh)
-    s = jnp.dot(q, k_ref[0].T,
+    s = jnp.dot(q, k_ref[...].T,
                 preferred_element_type=jnp.float32)         # (1, bk)
     s = jnp.where(valid_ref[...], s, NEG_INF)
 
@@ -44,7 +44,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_out_ref,
     l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
     m_ref[...] = m_new
     acc_ref[...] = (acc_ref[...] * corr
-                    + jnp.dot(p.astype(v_ref.dtype), v_ref[0],
+                    + jnp.dot(p.astype(v_ref.dtype), v_ref[...],
                               preferred_element_type=jnp.float32))
 
     @pl.when(kk == n_k - 1)
@@ -66,24 +66,29 @@ def flash_decode_partial(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = 1.0 / (dh ** 0.5)
 
     kern = functools.partial(_decode_kernel, n_k=n_k, scale=scale)
+    # one BH row per grid step, squeezed out of every block: q, valid and
+    # the outputs ride as (BH, 1, x) so each block's last two dims meet
+    # the TPU's (8, 128)-or-full rule (bk must then be the whole cache
+    # or a multiple of 128 when compiled for the chip)
+    row = lambda b, kk: (b, 0, 0)                          # noqa: E731
     o, m, l = pl.pallas_call(
         kern,
         grid=(bh, n_k),
         in_specs=[
-            pl.BlockSpec((1, dh), lambda b, kk: (b, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, kk: (b, kk, 0)),
-            pl.BlockSpec((1, bk, dh), lambda b, kk: (b, kk, 0)),
-            pl.BlockSpec((1, bk), lambda b, kk: (b, kk)),
+            pl.BlockSpec((None, 1, dh), row),
+            pl.BlockSpec((None, bk, dh), lambda b, kk: (b, kk, 0)),
+            pl.BlockSpec((None, bk, dh), lambda b, kk: (b, kk, 0)),
+            pl.BlockSpec((None, 1, bk), lambda b, kk: (b, 0, kk)),
         ],
         out_specs=[
-            pl.BlockSpec((1, dh), lambda b, kk: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, kk: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, kk: (b, 0)),
+            pl.BlockSpec((None, 1, dh), row),
+            pl.BlockSpec((None, 1, 1), row),
+            pl.BlockSpec((None, 1, 1), row),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, dh), jnp.float32),
-            jax.ShapeDtypeStruct((bh, 1), jnp.float32),
-            jax.ShapeDtypeStruct((bh, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, dh), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
@@ -91,8 +96,8 @@ def flash_decode_partial(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((1, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, valid)
-    return o, m, l
+    )(q.reshape(bh, 1, dh), k, v, valid.reshape(bh, 1, s))
+    return o.reshape(bh, dh), m.reshape(bh, 1), l.reshape(bh, 1)
 
 
 def flash_decode(q, k, v, valid, *, block_k: int = 512,

@@ -1,8 +1,12 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in CPU
-tests and on real hardware (the TARGET is TPU: compiled BlockSpec pipelines;
-interpret=True executes the kernel bodies in Python for validation).
+Whether a kernel runs compiled or in Pallas interpret mode is decided in
+ONE place, ``interpret_mode()``: interpret mode (the kernel body executed
+op by op, for validation) on the CPU backend only.  Every other backend
+runs the compiled kernel, so a kernel that the chip's compiler refuses
+fails loudly there instead of falling back.  The flag is a static jit
+argument, so a change of backend can never reuse a trace of the other
+mode.
 """
 from __future__ import annotations
 
@@ -21,8 +25,10 @@ from repro.kernels.streamed_matmul import (quantized_matmul as _qmatmul,
                                            streamed_matmul as _matmul)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def interpret_mode() -> bool:
+    """True only on the CPU backend, where Pallas TPU kernels cannot be
+    compiled and run in interpret mode instead."""
+    return jax.default_backend() == "cpu"
 
 
 # ---- autotuned defaults (kernels/autotune.py) ----------------------------
@@ -67,26 +73,27 @@ def _resolve_tiles(kernel: str, m: int, k: int, n: int, block_m, block_n,
             "block_k": block_k if block_k is not None else base["block_k"]}
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
-def _matmul_jit(x, w, *, block_m: int, block_n: int, block_k: int):
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
+                                             "interpret"))
+def _matmul_jit(x, w, *, block_m: int, block_n: int, block_k: int,
+                interpret: bool):
     return _matmul(x, w, block_m=block_m, block_n=block_n, block_k=block_k,
-                   interpret=not _on_tpu())
+                   interpret=interpret)
 
 
 def matmul(x, w, *, block_m: Optional[int] = None,
            block_n: Optional[int] = None, block_k: Optional[int] = None):
     tiles = _resolve_tiles("matmul", x.shape[0], x.shape[1], w.shape[1],
                            block_m, block_n, block_k)
-    return _matmul_jit(x, w, **tiles)
+    return _matmul_jit(x, w, **tiles, interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block_m", "block_n",
-                                             "block_k"))
+                                             "block_k", "interpret"))
 def _quant_matmul_jit(x, w_q, scale, *, bits: int, block_m: int,
-                      block_n: int, block_k: int):
+                      block_n: int, block_k: int, interpret: bool):
     return _qmatmul(x, w_q, scale, bits=bits, block_m=block_m,
-                    block_n=block_n, block_k=block_k,
-                    interpret=not _on_tpu())
+                    block_n=block_n, block_k=block_k, interpret=interpret)
 
 
 def quant_matmul(x, w_q, scale, *, bits: int = 8,
@@ -99,45 +106,54 @@ def quant_matmul(x, w_q, scale, *, bits: int = 8,
                            block_m, block_n, block_k)
     if bits == 4 and min(tiles["block_k"], k) % 2:
         tiles["block_k"] = _DEFAULT_TILES["block_k"]
-    return _quant_matmul_jit(x, w_q, scale, bits=bits, **tiles)
+    return _quant_matmul_jit(x, w_q, scale, bits=bits, **tiles,
+                             interpret=interpret_mode())
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "window", "block_q", "block_k"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
+                                             "block_k", "interpret"))
+def _attention_jit(q, k, v, *, causal, window, block_q, block_k, interpret):
+    return _flash_attn(q, k, v, causal=causal, window=window,
+                       block_q=block_q, block_k=block_k, interpret=interpret)
+
+
 def attention(q, k, v, *, causal: bool = True,
               window: Optional[int] = None, block_q: int = 256,
               block_k: int = 256):
-    return _flash_attn(q, k, v, causal=causal, window=window,
-                       block_q=block_q, block_k=block_k,
-                       interpret=not _on_tpu())
+    return _attention_jit(q, k, v, causal=causal, window=window,
+                          block_q=block_q, block_k=block_k,
+                          interpret=interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("block_k",))
+_decode_jit = jax.jit(_flash_decode, static_argnames=("block_k", "interpret"))
+_decode_partial_jit = jax.jit(_fd_partial,
+                              static_argnames=("block_k", "interpret"))
+_paged_decode_jit = jax.jit(_paged_decode, static_argnames=("interpret",))
+_paged_verify_jit = jax.jit(_paged_verify, static_argnames=("interpret",))
+
+
 def decode(q, k, v, valid, *, block_k: int = 512):
-    return _flash_decode(q, k, v, valid, block_k=block_k,
-                         interpret=not _on_tpu())
+    return _decode_jit(q, k, v, valid, block_k=block_k,
+                       interpret=interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("block_k",))
 def decode_partial(q, k, v, valid, *, block_k: int = 512):
-    return _fd_partial(q, k, v, valid, block_k=block_k,
-                       interpret=not _on_tpu())
+    return _decode_partial_jit(q, k, v, valid, block_k=block_k,
+                               interpret=interpret_mode())
 
 
-@jax.jit
 def paged_decode(q, k_pages, v_pages, tables, lengths):
     """Paged flash decode through per-row block tables, directly over
     the scheduler's (P, page, KV, dh) physical pool layout (tile size
     is the pool's page size; no relayout or densify)."""
-    return _paged_decode(q, k_pages, v_pages, tables, lengths,
-                         interpret=not _on_tpu())
+    return _paged_decode_jit(q, k_pages, v_pages, tables, lengths,
+                             interpret=interpret_mode())
 
 
-@jax.jit
 def paged_verify(q, k_pages, v_pages, tables, lengths):
     """Stacked multi-query paged decode (speculative verify): q is
     (B, W, KV, G, dh), query i of row b attends slots
     ``<= lengths[b] - W + i`` — one call scores a whole speculation
     window against the block-table pool."""
-    return _paged_verify(q, k_pages, v_pages, tables, lengths,
-                         interpret=not _on_tpu())
+    return _paged_verify_jit(q, k_pages, v_pages, tables, lengths,
+                             interpret=interpret_mode())

@@ -27,8 +27,7 @@ from repro.analysis.hlo import analyze_hlo
 from repro.analysis.roofline import (model_flops, params_count,
                                      roofline_terms)
 from repro.configs import get_config, list_archs, long_variant
-from repro.launch.mesh import (HBM_PER_CHIP, compat_make_mesh,
-                               compat_set_mesh, make_production_mesh)
+from repro.launch.mesh import HBM_PER_CHIP, make_mesh, make_production_mesh
 from repro.launch.specs import (INPUT_SHAPES, batch_pspecs, batch_specs,
                                 cache_pspecs, cache_specs, make_ctx, named)
 from repro.launch.stepfns import (make_prefill_step, make_serve_step,
@@ -79,9 +78,9 @@ def _mesh_for(tag: str):
         return make_production_mesh(multi_pod=(tag == "multipod"))
     # scaled-down dev meshes keep both axes >1
     if tag == "multipod":
-        return compat_make_mesh((2, max(n // 8, 1), 4),
+        return make_mesh((2, max(n // 8, 1), 4),
                                 ("pod", "data", "model"))
-    return compat_make_mesh((max(n // 4, 1), 4), ("data", "model"))
+    return make_mesh((max(n // 4, 1), 4), ("data", "model"))
 
 
 def dryrun_one(arch: str, shape_name: str, mesh_tag: str,
@@ -112,7 +111,7 @@ def dryrun_one(arch: str, shape_name: str, mesh_tag: str,
     bspecs = batch_specs(cfg, shape)
     b_pspecs = batch_pspecs(cfg, shape, ctx)
 
-    with compat_set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         if shape.kind == "train":
             pspecs = fsdp_pspecs(params_shape, mesh, base_specs)
             ctx = _with_layer_specs(ctx, cfg, pspecs)

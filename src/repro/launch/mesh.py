@@ -9,40 +9,25 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
-
-try:  # jax >= 0.5 wants explicit axis types; Auto matches older behaviour
-    from jax.sharding import AxisType
-
-    def _axis_types_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # pragma: no cover - jax < 0.5: Auto is the only mode
-    def _axis_types_kw(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across the AxisType API break (jax 0.4 vs >= 0.5)."""
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
-
-
-def compat_set_mesh(mesh):
-    """Context manager entering ``mesh``: jax.sharding.set_mesh on >= 0.5,
-    the Mesh object's own context manager on 0.4."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     model = min(model, n)
-    return compat_make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def batch_axes_of(mesh) -> Tuple[str, ...]:

@@ -74,12 +74,14 @@ from __future__ import annotations
 
 import argparse
 import json
+import tempfile
 import time
 from pathlib import Path
 
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.analysis.report import (drift_report, format_drift,
                                    format_peak_breakdown,
                                    peak_breakdown_report)
@@ -91,16 +93,27 @@ from repro.data.traces import (load_trace, make_trace, submit_trace,
                                trace_max_len)
 from repro.models.api import build_model
 
-CKPT_ROOT = Path("/tmp/repro_ckpts")
+CKPT_ROOT = Path(tempfile.gettempdir()) / "repro_ckpts"
 QUANT_CHOICES = ("fp32", "int8", "int4", "auto")
+
+
+def init_params(cfg, seed: int = 0):
+    """The model's random weights from ``seed``, made on the host CPU
+    where that backend is up: a model larger than the device's budget
+    must never have to sit on the device whole, and the same seed gives
+    the same weights to every caller (the checkpoint and a reference)."""
+    try:
+        host = jax.local_devices(backend="cpu")[0]
+    except RuntimeError:           # JAX_PLATFORMS left the CPU backend out
+        host = None
+    with jax.default_device(host):
+        return build_model(cfg).init(jax.random.PRNGKey(seed))
 
 
 def ensure_checkpoint(cfg, seed: int = 0) -> Path:
     path = CKPT_ROOT / cfg.name.replace("/", "_")
     if not (path / "manifest.json").exists():
-        api = build_model(cfg)
-        params = api.init(jax.random.PRNGKey(seed))
-        partition_and_save(params, cfg, path)
+        partition_and_save(init_params(cfg, seed), cfg, path)
     return path
 
 
@@ -142,6 +155,7 @@ def run(arch: str, *, budget_mb: float | None = None, requests: int = 4,
         slo_shed: bool = False, trace_out: str | None = None,
         metrics_out: str | None = None):
     assert quant in QUANT_CHOICES, quant
+    compile_cache.enable()
     # fresh telemetry per run: zero the registry IN PLACE (cached
     # instruments stay wired) and install a recording tracer only when a
     # timeline export was requested — tracing off costs nothing

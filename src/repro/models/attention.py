@@ -16,13 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-try:  # jax>=0.6 moved shard_map to the top level
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod  # type: ignore[assignment]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import common
@@ -375,13 +369,15 @@ def _combine_partials(o, m, l):
 
 def _pallas_decode(q, k_cache, v_cache, valid):
     """Route single-device decode through the Pallas flash-decoding kernel
-    (kernels/flash_decode.py; interpret=True off-TPU via kernels.ops).
+    (kernels/flash_decode.py; interpret mode on the CPU backend only, via
+    kernels.ops).
 
     The kernel works in a flat (BH, ...) layout with one KV row per query
     head, so the grouped cache is broadcast across the G query heads — the
     G-fold read amplification is the price of the kernel's HBM->VMEM
     streaming pipeline and only applies on this explicitly-requested path.
-    Requires dhk == dhv (GQA; MLA's asymmetric latent head falls back).
+    Requires dhk == dhv: ``core.modules.resolve_attn_impl`` never selects
+    Pallas for MLA's asymmetric latent head.
 
     Batched ragged decode rides through unchanged: the (B, S) ``valid``
     mask is per ROW, so a stacked batch of requests at different cache
@@ -392,6 +388,9 @@ def _pallas_decode(q, k_cache, v_cache, valid):
 
     b, kv, g, dh = q.shape
     s, dv = k_cache.shape[1], v_cache.shape[-1]
+    if dv != dh:
+        raise ValueError(f"Pallas decode needs equal K/V head widths, got "
+                         f"{dh} and {dv}")
     bh = b * kv * g
     qf = q.reshape(bh, dh)
     kf = jnp.broadcast_to(k_cache.transpose(0, 2, 1, 3)[:, :, None],
@@ -400,7 +399,9 @@ def _pallas_decode(q, k_cache, v_cache, valid):
                           (b, kv, g, s, dv)).reshape(bh, s, dv)
     validf = jnp.broadcast_to(valid[:, None, None], (b, kv, g, s)
                               ).reshape(bh, s)
-    out = ops.decode(qf, kf, vf, validf, block_k=_pick_block(s, 512))
+    # the chip needs a lane-aligned (multiple of 128) or whole-cache block
+    bk = next((c for c in (512, 256, 128) if s % c == 0), s)
+    out = ops.decode(qf, kf, vf, validf, block_k=bk)
     return out.reshape(b, kv, g, dv)
 
 
@@ -416,8 +417,7 @@ def flash_decode(q, k_cache, v_cache, valid, ctx: Optional[ShardingCtx],
     selects it so the cache streams HBM -> VMEM in blocks.
     """
     if ctx is None:
-        if (impl == "pallas"
-                and k_cache.shape[-1] == v_cache.shape[-1]):
+        if impl == "pallas":
             return _pallas_decode(q, k_cache, v_cache, valid)
         o, m, l = _decode_partial(q, k_cache, v_cache, valid)
         return _combine_partials(o[None], m[None], l[None]).astype(v_cache.dtype)

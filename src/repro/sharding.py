@@ -8,21 +8,10 @@ device) falls back to purely local dense paths.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 from typing import Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - jax < 0.6 location
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-# replication-check kwarg renamed check_rep -> check_vma across jax versions
-_NO_REP_CHECK = {
-    ("check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-     else "check_rep"): False}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,8 +74,6 @@ def constrain_layer_params(ctx: Optional[ShardingCtx], layer_params):
         return layer_params
     from jax.sharding import PartitionSpec as _P
 
-    shard_map = _shard_map
-
     def f(x, spec: _P):
         if not isinstance(spec, _P):
             return x
@@ -107,12 +94,12 @@ def constrain_layer_params(ctx: Optional[ShardingCtx], layer_params):
         def gather(w):
             return jax.lax.all_gather(w, gather_axes, axis=axis, tiled=True)
 
-        # replication check off (check_vma on jax >= 0.6, check_rep
-        # before): the checker can't statically prove all-gather output
-        # replication, but a full tiled all_gather over 'data' is
-        # replicated on that axis by construction
-        return shard_map(gather, mesh=ctx.mesh, in_specs=_P(*entries),
-                         out_specs=_P(*out_entries), **_NO_REP_CHECK)(x)
+        # replication check off: the checker can't statically prove
+        # all-gather output replication, but a full tiled all_gather over
+        # 'data' is replicated on that axis by construction
+        return jax.shard_map(gather, mesh=ctx.mesh, in_specs=_P(*entries),
+                             out_specs=_P(*out_entries),
+                             check_vma=False)(x)
 
     return jax.tree.map(f, layer_params, ctx.layer_param_specs,
                         is_leaf=lambda v: isinstance(v, _P))

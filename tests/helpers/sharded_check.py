@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.data.synthetic import make_batch
-from repro.launch.mesh import compat_make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.api import build_model, param_pspecs
 from repro.models.config import DENSE, MOE, ModelConfig
 from repro.sharding import ShardingCtx
@@ -24,7 +24,7 @@ from repro.sharding import ShardingCtx
 
 def main():
     assert len(jax.devices()) == 8, jax.devices()
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ShardingCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
 
     # ---- MoE expert-parallel loss == local loss

@@ -156,7 +156,6 @@ def test_pool_interleaving_property(seed, n_reqs, page_size):
     hw = 0
     for step in range(40):
         assert led.resident == pool.mapped_bytes       # ledger exact
-        hw = max(hw, pool.mapped_pages)
         op = rng.integers(0, 3)
         if op == 0 and len(live) < n_reqs:             # admit
             toks = rng.integers(0, 3, rng.integers(1, 10)).tolist()
@@ -167,7 +166,10 @@ def test_pool_interleaving_property(seed, n_reqs, page_size):
         elif op == 2 and live:                          # retire
             k = rng.choice(list(live))
             live.pop(k).release_all(pool, tree)
-        assert pool.capacity <= max(hw, pool.mapped_pages)  # high-water
+        # high-water sampled AFTER the op, so pages the last step maps
+        # count toward the final capacity check
+        hw = max(hw, pool.mapped_pages)
+        assert pool.capacity <= hw
     for t in list(live.values()):
         t.release_all(pool, tree)
     assert pool.mapped_pages == 0 and led.resident == 0  # exact drain

@@ -119,3 +119,29 @@ def test_hermes_planner_end_to_end(gpt2s, toks):
     assert agents[0] <= agents[1] <= agents[2] or lats[0] >= lats[2]
     assert lats[0] >= lats[2] - 1e-9
     assert all(e.feasible for e in entries)
+
+
+def test_profile_cache_keyed_by_device(tmp_path, monkeypatch):
+    """A profile cached by one device never plans a run on another: the
+    checkpoint directory keeps one ``profile.<device kind>.json`` each."""
+    from repro.core import hermes as hermes_mod
+    from repro.core.profiler import save_profile
+
+    cfg = get_config("gpt2_base").reduced()
+    save_profile({"measured_on": "cpu"}, tmp_path / "profile.cpu.json")
+    monkeypatch.setattr(hermes_mod, "device_arch", lambda: "cpu")
+    assert Hermes(tmp_path, cfg).profile() == {"measured_on": "cpu"}
+
+    runs = []
+
+    def measure(*args, **kw):
+        runs.append(args)
+        return {"measured_on": "tpu-v5-lite"}
+
+    monkeypatch.setattr(hermes_mod, "profile_model", measure)
+    monkeypatch.setattr(hermes_mod, "device_arch", lambda: "tpu-v5-lite")
+    assert Hermes(tmp_path, cfg).profile() == {"measured_on": "tpu-v5-lite"}
+    assert len(runs) == 1                  # measured, not loaded
+    assert (tmp_path / "profile.tpu-v5-lite.json").exists()
+    monkeypatch.setattr(hermes_mod, "device_arch", lambda: "cpu")
+    assert Hermes(tmp_path, cfg).profile() == {"measured_on": "cpu"}
