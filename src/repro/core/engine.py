@@ -63,6 +63,7 @@ itself) and shrinks under admission pressure via LRU eviction.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import sys
@@ -502,6 +503,8 @@ class PipeloadEngine:
                             if s["kind"] == "layer"]
         # persistent across pipeline rounds (pinning / non-destroying modes)
         self._resident: Dict[str, dict] = {}
+        # the prefetch stream of the round in progress (telemetry only)
+        self._stream_id: Optional[int] = None
         # ONE async prefetch runtime for every byte mover: the PIPELOAD
         # Loading Agents stream shard rounds through it and the expert
         # engine demand-loads on the same pool (core/prefetch.py)
@@ -556,9 +559,55 @@ class PipeloadEngine:
 
     # ------------------------------------------------------------------
     def _load(self, name: str) -> dict:
-        """Disk -> host -> device ("memory" tier)."""
-        host = load_shard(self.dir, name)
-        return jax.tree.map(jnp.asarray, host)
+        """Disk -> host -> device ("memory" tier).  Traced as
+        ``shard_read`` (the parse into host arrays) and ``shard_put``
+        (issuing the host-to-device copies, which may still be in
+        flight when it ends), with the round's stream number when a
+        round's Loading Agent makes the load."""
+        tr = _tele.get_tracer()
+        if not tr.enabled:
+            return jax.tree.map(jnp.asarray, load_shard(self.dir, name))
+        args = {"key": name, "bytes": int(self.shards[name]["bytes"])}
+        if self._stream_id is not None:
+            args["stream"] = self._stream_id
+        with tr.span("shard_read", **args):
+            host = load_shard(self.dir, name)
+        with tr.span("shard_put", **args):
+            return jax.tree.map(jnp.asarray, host)
+
+    @contextlib.contextmanager
+    def _round(self):
+        """Holds the number the round's prefetch stream takes (made next,
+        by this thread) until the stream has closed."""
+        self._stream_id = self.runtime.streams
+        try:
+            yield self._stream_id
+        finally:
+            self._stream_id = None
+
+    # Inference-agent steps of one layer, traced as ``layer_dispatch``
+    # (the jitted call returns once its work is enqueued) and
+    # ``layer_sync`` (the wait for that work to finish)
+    def _layer_args(self, k: int) -> dict:
+        args = {"layer": self.layer_names[k]}
+        if self._stream_id is not None:
+            args["stream"] = self._stream_id
+        return args
+
+    def _dispatch(self, k: int, fn: Callable, *args):
+        tr = _tele.get_tracer()
+        if not tr.enabled:
+            return fn(*args)
+        with tr.span("layer_dispatch", **self._layer_args(k)):
+            return fn(*args)
+
+    def _sync(self, k: int, x) -> None:
+        tr = _tele.get_tracer()
+        if not tr.enabled:
+            x.block_until_ready()
+            return
+        with tr.span("layer_sync", **self._layer_args(k)):
+            x.block_until_ready()
 
     # Per-layer apply paths.  Expert-split MoE checkpoints route through
     # the ExpertStreamEngine (router -> demand-load union -> combine);
@@ -566,8 +615,8 @@ class PipeloadEngine:
     def _apply_layer(self, weights, x, k: int = 0):
         if self.expert is not None:
             return self.expert.layer(self.layer_names[k], weights, x)
-        y = self.fns["layer"](weights, x)
-        y.block_until_ready()
+        y = self._dispatch(k, self.fns["layer"], weights, x)
+        self._sync(k, y)
         return y
 
     def _layer_cache(self, k: int, weights, x, total_len: int):
@@ -613,18 +662,22 @@ class PipeloadEngine:
         # window) ride along uncharged as ``preloaded`` entries.
         preloaded = {k: self._resident[names[k]] for k in range(n)
                      if names[k] in self._resident}
-        stream = self.runtime.stream(
-            names, [self.shards[nm]["bytes"] for nm in names], self._load,
-            ledger=ledger, preloaded=preloaded, events=events, t0=t0)
-
-        # ---- Inference Agent (this thread): in-order inference queue
         tr = _tele.get_tracer()
-        with stream, tr.span("stream_round", layers=n):
+        with self._round() as sid, self.runtime.stream(
+                names, [self.shards[nm]["bytes"] for nm in names],
+                self._load, ledger=ledger, preloaded=preloaded,
+                events=events, t0=t0) as stream, \
+                tr.span("stream_round", layers=n, stream=sid):
+            # ---- Inference Agent (this thread): in-order inference queue
             for k in range(n):
-                w = stream.wait(k)                   # S_comp(k)
+                if tr.enabled:
+                    with tr.span("shard_wait", layer=names[k], stream=sid):
+                        w = stream.wait(k)           # S_comp(k)
+                else:
+                    w = stream.wait(k)               # S_comp(k)
                 t = time.perf_counter()
                 if tr.enabled:
-                    with tr.span("compute", layer=names[k]):
+                    with tr.span("compute", layer=names[k], stream=sid):
                         x = apply_fn(k, w, x)
                 else:
                     x = apply_fn(k, w, x)
@@ -1288,26 +1341,29 @@ class PipeloadEngine:
                 # request's window [pos, pos+W) scores in one pass
                 fn = (self.fns["layer_verify_paged"] if dx.shape[1] > 1
                       else self.fns["layer_decode_paged"])
-                dx, paged_pools[names[k]] = fn(
-                    w, dx, paged_pools[names[k]], decode_tables,
+                dx, paged_pools[names[k]] = self._dispatch(
+                    k, fn, w, dx, paged_pools[names[k]], decode_tables,
                     decode_pos)
-                dx.block_until_ready()
+                self._sync(k, dx)
             elif dx is not None:
-                dx, decode_caches[names[k]] = self._layer_decode(
-                    k, w, dx, decode_caches[names[k]], decode_pos)
-                dx.block_until_ready()
+                dx, decode_caches[names[k]] = self._dispatch(
+                    k, self._layer_decode, k, w, dx, decode_caches[names[k]],
+                    decode_pos)
+                self._sync(k, dx)
             if cx is not None:
                 # chunk windows ride the same verify module at width C,
                 # against their OWN tables/positions (disjoint writes:
                 # chunk slots are prompt positions in the chunkers'
                 # pages; any shared page gets bitwise-identical bytes)
-                cx, paged_pools[names[k]] = self.fns["layer_verify_paged"](
-                    w, cx, paged_pools[names[k]], chunk_tables, chunk_pos)
-                cx.block_until_ready()
+                cx, paged_pools[names[k]] = self._dispatch(
+                    k, self.fns["layer_verify_paged"], w, cx,
+                    paged_pools[names[k]], chunk_tables, chunk_pos)
+                self._sync(k, cx)
             nxt = []
             for i, px in enumerate(pxs):
-                px, cache = self._layer_cache(k, w, px, prefill_total)
-                px.block_until_ready()
+                px, cache = self._dispatch(k, self._layer_cache, k, w, px,
+                                           prefill_total)
+                self._sync(k, px)
                 prefill_caches[i][names[k]] = cache
                 nxt.append(px)
             return dx, cx, nxt

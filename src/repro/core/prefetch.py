@@ -94,8 +94,10 @@ class PrefetchStream:
                  sizes: Sequence[int], load_fn: Callable[[str], dict], *,
                  ledger=None, preloaded: Optional[Dict[int, dict]] = None,
                  events: Optional[list] = None, t0: float = 0.0,
-                 retries: Optional[int] = None, owner: str = "stream"):
+                 retries: Optional[int] = None, owner: str = "stream",
+                 sid: int = 0):
         assert len(keys) == len(sizes)
+        self.sid = sid                  # the runtime's number for it
         self._runtime = runtime
         self._load_fn = load_fn
         self._ledger = ledger
@@ -184,7 +186,7 @@ class PrefetchStream:
                 return
             if tr.enabled:
                 with tr.span("shard_acquire", key=job.key,
-                             bytes=job.nbytes):
+                             bytes=job.nbytes, stream=self.sid):
                     ok = self._acquire(job)
             else:
                 ok = self._acquire(job)
@@ -199,7 +201,7 @@ class PrefetchStream:
                     t_start = time.perf_counter()
                     if tr.enabled:
                         with tr.span("shard_load", key=job.key,
-                                     bytes=job.nbytes):
+                                     bytes=job.nbytes, stream=self.sid):
                             w = self._load_fn(job.key)
                     else:
                         w = self._load_fn(job.key)
@@ -217,7 +219,8 @@ class PrefetchStream:
             self._event("load_start", job.key, t_start)
             self._event("load_end", job.key, time.perf_counter())
             if tr.enabled:
-                tr.instant("shard_publish", key=job.key, bytes=job.nbytes)
+                tr.instant("shard_publish", key=job.key, bytes=job.nbytes,
+                           stream=self.sid)
             with self._cond:
                 if self._done.is_set():
                     abort = True
@@ -275,7 +278,8 @@ class PrefetchStream:
         """Drainer-side: free the weights and return the charge."""
         tr = _tele.get_tracer()
         if tr.enabled:
-            with tr.span("shard_destroy", key=job.key, bytes=job.nbytes):
+            with tr.span("shard_destroy", key=job.key, bytes=job.nbytes,
+                         stream=self.sid):
                 self._finalize_destroy_inner(job, weights)
         else:
             self._finalize_destroy_inner(job, weights)
@@ -369,6 +373,8 @@ class PrefetchRuntime:
         self._destroy_cond = threading.Condition()
         self._drainer: Optional[threading.Thread] = None
         self._shutdown = False
+        # streams made so far, which is the number the next one takes
+        self.streams = 0
 
     # -- worker pools ------------------------------------------------------
     # Two pools, not one: stream workers can PARK — a budgeted loader
@@ -463,10 +469,15 @@ class PrefetchRuntime:
         """One round's ordered prefetch over ``keys`` (``preloaded`` maps
         already-resident indices to their weights: published immediately,
         never charged).  ``owner`` tags every ledger charge the stream
-        makes (see engine.LEDGER_OWNERS)."""
+        makes (see engine.LEDGER_OWNERS).  Streams are numbered in the
+        order they are made (``PrefetchStream.sid``): every telemetry span
+        of one round carries that number as ``stream``."""
+        with self._lock:
+            sid = self.streams
+            self.streams += 1
         return PrefetchStream(self, keys, sizes, load_fn, ledger=ledger,
                               preloaded=preloaded, events=events, t0=t0,
-                              retries=retries, owner=owner)
+                              retries=retries, owner=owner, sid=sid)
 
     # -- teardown ----------------------------------------------------------
     @property

@@ -1063,7 +1063,16 @@ class BatchScheduler:
 
     def step(self) -> bool:
         """One round boundary + (if there is work) one pipeline round.
-        Returns False once every submitted request has retired."""
+        Returns False once every submitted request has retired.  Traced
+        as ``serve_round``: its time outside the round's ``stream_round``
+        is the scheduler's own host work."""
+        tr = _tele.get_tracer()
+        if not tr.enabled:
+            return self._step(None)
+        with tr.span("serve_round", round=self.round) as span:
+            return self._step(span)
+
+    def _step(self, span) -> bool:
         eng = self.engine
         now = time.perf_counter() - self._t0
         for r in self.queue:
@@ -1089,9 +1098,9 @@ class BatchScheduler:
         self.events.append((time.perf_counter() - t0, "round",
                             str(self.round)))
         tr = _tele.get_tracer()
-        if tr.enabled:
-            tr.instant("serve_round", round=self.round,
-                       inflight=len(self.inflight) + len(admitted))
+        if span is not None:
+            span.set(rows=len(self.inflight) + len(admitted),
+                     stream=eng.runtime.streams)
         # serving-tier round shape: DECODERS advance one token through
         # the stacked decode batch; CHUNKERS (mid-chunked-prefill, plus
         # this boundary's long-prompt admissions) feed one C-token chunk

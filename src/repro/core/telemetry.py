@@ -23,6 +23,14 @@ draft/verify/rollback loop.  Three pieces:
     one track per worker thread, "C" counter tracks, "i" policy
     instants) and ``summary_table`` renders a plain-text metric table.
 
+One clock with the device trace: an enabled span also enters a
+``jax.profiler.TraceAnnotation`` named ``hermes.<name>`` with the span's
+arguments as its metadata, so under a ``jax.profiler`` session every
+program span, from every thread, sits on the profiler's timeline beside
+the device ops.  Enabling a tracer also registers (once per process) a
+listener for JAX's backend-compile durations that records a
+``jit_compile`` span on the compiling thread while a tracer is on.
+
 Zero-cost when disabled: the module-level tracer defaults to
 ``NULL_TRACER``, whose ``span()`` returns the shared ``NULL_SPAN``
 singleton — no span object, no buffer append.  Hot paths (per-layer
@@ -42,9 +50,11 @@ from pathlib import Path
 from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
+from jax import monitoring as _monitoring
+from jax.profiler import TraceAnnotation
 
 __all__ = [
-    "Tracer", "NullTracer", "NULL_TRACER", "NULL_SPAN",
+    "Tracer", "NullTracer", "NULL_TRACER", "NULL_SPAN", "PROFILER_PREFIX",
     "get_tracer", "enable", "disable",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "metrics",
     "counter_values", "export_chrome_trace", "summary_table",
@@ -55,10 +65,17 @@ __all__ = [
 # ===========================================================================
 # Span tracer
 # ===========================================================================
-class _Span:
-    """Live span: records on ``__exit__`` so nested spans order by end."""
+PROFILER_PREFIX = "hermes."
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+
+class _Span:
+    """Live span: records on ``__exit__`` so nested spans order by end.
+    Also a ``hermes.<name>`` profiler annotation carrying the span's
+    arguments (scalars: str, int or float), which lands in a
+    ``jax.profiler`` trace when one is being taken."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self._tracer = tracer
@@ -66,12 +83,22 @@ class _Span:
         self._args = args
 
     def __enter__(self) -> "_Span":
+        self._ann = TraceAnnotation(PROFILER_PREFIX + self._name,
+                                    **self._args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tracer._record_span(self._name, self._t0,
-                                  time.perf_counter(), self._args)
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._tracer._record_span(self._name, self._t0, t1, self._args)
+
+    def set(self, **args) -> None:
+        """Add arguments known only once the span has begun (both sinks
+        receive them)."""
+        self._args.update(args)
+        self._ann.set_metadata(**args)
 
 
 class _NullSpan:
@@ -159,6 +186,8 @@ class Tracer:
 
 
 _active: object = NULL_TRACER
+_compile_listener = False
+_compile_listener_lock = threading.Lock()
 
 
 def get_tracer():
@@ -166,9 +195,26 @@ def get_tracer():
     return _active
 
 
+def _on_duration(event: str, duration_secs: float, **kw) -> None:
+    """JAX monitoring listener: a backend compile (or compile-cache
+    load) becomes a ``jit_compile`` span ending now, on the compiling
+    thread, while a tracer is on."""
+    tr = _active
+    if event == COMPILE_EVENT and tr.enabled:
+        t1 = time.perf_counter()
+        tr._record_span("jit_compile", t1 - duration_secs, t1,
+                        {"fun": str(kw.get("fun_name", ""))})
+
+
 def enable(tracer: Optional[Tracer] = None) -> Tracer:
     """Install (and return) a recording tracer as the process-wide one."""
-    global _active
+    global _active, _compile_listener
+    with _compile_listener_lock:
+        if not _compile_listener:
+            # JAX listeners cannot be removed: one per process, idle
+            # while tracing is off
+            _monitoring.register_event_duration_secs_listener(_on_duration)
+            _compile_listener = True
     _active = tracer if tracer is not None else Tracer()
     return _active
 

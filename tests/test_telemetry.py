@@ -1,11 +1,14 @@
 """Telemetry suite: the zero-cost disabled path (no-op singleton
 identity — the CI overhead guard), tracer recording + Chrome-trace
-export structure, metrics-registry semantics (reset-in-place), the
-golden structural trace over a deterministic 2-request serve
+export structure, spans as ``hermes.*`` profiler annotations, the
+``jit_compile`` listener, metrics-registry semantics (reset-in-place),
+the golden structural trace over a deterministic 2-request serve
 (regenerate with ``REPRO_UPDATE_GOLDEN=1``, mirroring
-tests/golden/serve_slo_trace.json), the enabled-vs-disabled
-token/ledger identity property, fault-outcome surfacing in
-RunStats/ServeStats and the planner drift report."""
+tests/golden/serve_slo_trace.json), how a traced serve's spans nest and
+share a round's ``stream`` id, the enabled-vs-disabled token/ledger
+identity property, fault-outcome surfacing in RunStats/ServeStats and
+the planner drift report."""
+import glob
 import json
 import os
 import threading
@@ -14,6 +17,7 @@ from types import SimpleNamespace
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.analysis.report import drift_report, format_drift
@@ -85,6 +89,40 @@ def test_disabled_path_allocates_nothing():
     assert tr.counter("ledger_resident_bytes", 7) is None
 
 
+class _SpyNullTracer(tele.NullTracer):
+    """A disabled tracer that notes every span a call site asks it for:
+    a site guarded by ``tracer.enabled`` never asks (and so builds no
+    argument dict)."""
+
+    def __init__(self):
+        self.asked = []
+
+    def span(self, name: str, **args):
+        self.asked.append(name)
+        return super().span(name, **args)
+
+
+GUARDED = ("shard_wait", "layer_dispatch", "layer_sync", "shard_read",
+           "shard_put", "serve_round", "shard_acquire", "shard_load",
+           "shard_destroy", "compute")
+
+
+def test_disabled_path_skips_the_guarded_sites(tiny):
+    """With tracing off, the per-layer, per-shard and per-round sites
+    added for the profiler's timeline hand back ``NULL_SPAN`` if asked,
+    and in a real serve they are never asked at all."""
+    tr = tele.get_tracer()
+    for name in GUARDED:
+        assert tr.span(name, layer="h.0", stream=3) is tele.NULL_SPAN
+    spy = _SpyNullTracer()
+    tele.enable(spy)
+    assert tele.get_tracer() is spy and not spy.enabled
+    cfg, path = tiny
+    _, stats = _serve(cfg, path, requests=1, new_tokens=2)
+    assert stats.new_tokens > 0
+    assert spy.asked and set(spy.asked) == {"stream_round"}
+
+
 def test_enable_disable_roundtrip():
     t = tele.enable()
     assert tele.get_tracer() is t and t.enabled
@@ -129,6 +167,68 @@ def test_export_chrome_trace_structure(tmp_path):
     assert [e["args"]["value"] for e in cs] == [3.0, 5.0]
     assert all(e["ts"] >= 0 and e["dur"] >= 0 if e["ph"] == "X"
                else e.get("ts", 0) >= 0 for e in evs if e["ph"] != "M")
+
+
+def _hermes_events(trace_dir):
+    """``hermes.*`` events of a profiler trace: (name, line, stats), a
+    line being one thread's, told apart by its place in the plane."""
+    from jax.profiler import ProfileData
+
+    (xp,) = glob.glob(str(Path(trace_dir) / "**" / "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(xp).planes:
+        if plane.name.startswith("/host:"):
+            for i, line in enumerate(plane.lines):
+                out += [(e.name, (plane.name, i), dict(e.stats))
+                        for e in line.events
+                        if e.name.startswith(tele.PROFILER_PREFIX)]
+    return out
+
+
+def test_spans_land_in_the_profiler_trace(tmp_path):
+    """Under a ``jax.profiler`` session an enabled span, on the main
+    thread and on a worker, is a ``hermes.<name>`` host event with its
+    arguments (and those set later) as stats."""
+    t = tele.enable()
+
+    def work():
+        with t.span("shard_read", key="h.1", bytes=4096, stream=2):
+            pass
+
+    with jax.profiler.trace(str(tmp_path)):
+        with t.span("serve_round", round=5) as sp:
+            th = threading.Thread(target=work, name="pipeload-worker_0")
+            th.start()
+            th.join()
+            jnp.ones(3).block_until_ready()
+            sp.set(rows=4, stream=2)
+    evs = {name: (line, stats) for name, line, stats
+           in _hermes_events(tmp_path)}
+    assert evs["hermes.shard_read"][1] == {"key": "h.1", "bytes": 4096,
+                                           "stream": 2}
+    assert evs["hermes.serve_round"][1] == {"round": 5, "rows": 4,
+                                            "stream": 2}
+    assert evs["hermes.shard_read"][0] != evs["hermes.serve_round"][0]
+    # the buffers hold the same spans, the late arguments included
+    by = {s[0]: s for s in t.spans}
+    assert by["serve_round"][4] == {"round": 5, "rows": 4, "stream": 2}
+    assert by["shard_read"][1] == "pipeload-worker_0"
+
+
+def test_jit_compile_span_while_enabled():
+    """A backend compile records a ``jit_compile`` span on the compiling
+    thread while a tracer is on, and nothing once it is off."""
+    t = tele.enable()
+    scale = float(np.random.default_rng().integers(1, 10 ** 9))
+    jax.jit(lambda x: x * scale + 1.0)(jnp.ones(5)).block_until_ready()
+    compiles = [s for s in t.spans if s[0] == "jit_compile"]
+    assert compiles and compiles[-1][1] == "MainThread"
+    assert all(e >= s for _, _, s, e, _ in compiles)
+    tele.disable()
+    n = len(t.spans)
+    jax.jit(lambda x: x * scale - 1.0)(jnp.ones(5)).block_until_ready()
+    assert len(t.spans) == n
 
 
 def test_export_requires_enabled(tmp_path):
@@ -193,7 +293,9 @@ def test_golden_trace_structure(tiny):
         tele.disable()
     assert stats.requests == 2 and stats.new_tokens > 0
     got = {
-        "spans": sorted({s[0] for s in tracer.spans}),
+        # whether a jit_compile span fires depends on the process's
+        # compile caches, not on the serve
+        "spans": sorted({s[0] for s in tracer.spans} - {"jit_compile"}),
         "instants": sorted({i[0] for i in tracer.instants}),
         "counters": sorted({c[0] for c in tracer.counters}),
         "tracks": sorted({_track(s[1]) for s in tracer.spans}
@@ -232,6 +334,69 @@ def test_traced_serve_exports_loadable_json(tiny, tmp_path):
     assert any(e["ph"] == "C" and e["name"] == "ledger_resident_bytes"
                for e in loaded["traceEvents"])
     assert trace == loaded
+
+
+def _inside(inner, outer) -> bool:
+    return outer[2] <= inner[2] and inner[3] <= outer[3]
+
+
+def test_traced_serve_spans_nest_and_share_the_round(tiny):
+    """A traced paged serve: the loading agent's parse and device put lie
+    inside its ``shard_load``, the inference agent's dispatch and sync
+    inside ``compute``, the wait for a shard inside ``stream_round`` and
+    outside ``compute``, every round inside a ``serve_round``; and one
+    round's spans, on every thread, carry the same ``stream`` id."""
+    cfg, path = tiny
+    tracer = tele.enable()
+    try:
+        _serve(cfg, path, requests=2, new_tokens=3)
+    finally:
+        tele.disable()
+    by = {}
+    for s in tracer.spans:
+        by.setdefault(s[0], []).append(s)
+
+    def parent(s, name):
+        found = [p for p in by[name] if p[1] == s[1] and _inside(s, p)]
+        assert len(found) == 1, (s, name)
+        return found[0]
+
+    for name in ("shard_read", "shard_put"):
+        # streamed loads; embed and head load once, outside any round
+        streamed = [s for s in by[name] if "stream" in s[4]]
+        assert streamed
+        assert {s[4]["key"] for s in by[name]
+                if "stream" not in s[4]} <= {"embed", "head"}
+        for s in streamed:
+            assert s[4] == parent(s, "shard_load")[4]
+    for name in ("layer_dispatch", "layer_sync"):
+        assert by[name]
+        for s in by[name]:
+            comp = parent(s, "compute")
+            assert s[4] == comp[4]
+    for s in by["shard_wait"]:
+        rnd = parent(s, "stream_round")
+        assert s[4]["stream"] == rnd[4]["stream"]
+        assert not any(c[1] == s[1] and _inside(c, s) or _inside(s, c)
+                       for c in by["compute"])
+    rounds = by["stream_round"]
+    assert rounds and len({r[4]["stream"] for r in rounds}) == len(rounds)
+    for r in rounds:
+        serve = parent(r, "serve_round")
+        assert serve[4]["stream"] == r[4]["stream"]
+        assert serve[4]["rows"] >= 1
+    # agent spans of round s belong to round s's window: issued after the
+    # round's stream opened and finished before it closed
+    agent = [s for name in ("shard_acquire", "shard_load", "shard_read",
+                            "shard_put") for s in by[name]
+             if "stream" in s[4]]
+    assert {s[4]["stream"] for s in agent} == {r[4]["stream"]
+                                               for r in rounds}
+    for s in agent:
+        (rnd,) = [r for r in rounds if r[4]["stream"] == s[4]["stream"]]
+        serve = parent(rnd, "serve_round")
+        assert serve[2] <= s[2] and s[3] <= serve[3]
+        assert s[1] != "MainThread"
 
 
 # ---------------------------------------------------------------------------
