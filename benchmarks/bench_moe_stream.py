@@ -21,11 +21,13 @@ activated experts), reported as ``tok_agree``.
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from repro.configs import get_config
 from repro.core import PipeloadEngine
-from repro.checkpoint import load_manifest, partition_and_save
+from repro.checkpoint import ensure_partitioned, load_manifest
 from repro.models.api import build_model
 from benchmarks.common import CKPT_ROOT, csv_line, emit
 
@@ -47,11 +49,10 @@ def _config():
 def ensure_ckpts(cfg):
     paths = {"whole": CKPT_ROOT / "moe_stream_whole",
              "split": CKPT_ROOT / "moe_stream_split"}
-    if not all((p / "manifest.json").exists() for p in paths.values()):
-        params = build_model(cfg).init(jax.random.PRNGKey(0))
-        partition_and_save(params, cfg, paths["whole"], expert_split=False)
-        partition_and_save(params, cfg, paths["split"], expert_split=True)
-        del params
+    params = functools.cache(
+        lambda: build_model(cfg).init(jax.random.PRNGKey(0)))
+    ensure_partitioned(paths["whole"], cfg, params, expert_split=False)
+    ensure_partitioned(paths["split"], cfg, params, expert_split=True)
     return paths
 
 

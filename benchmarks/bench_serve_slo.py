@@ -29,7 +29,7 @@ import time
 import jax
 import numpy as np
 
-from repro.checkpoint import partition_and_save
+from repro.checkpoint import ensure_partitioned
 from repro.configs import get_config
 from repro.core import BatchScheduler, PipeloadEngine
 from repro.core.scheduler import SLO
@@ -55,11 +55,9 @@ def _cfg():
 
 
 def _ckpt(cfg):
-    path = CKPT_ROOT / "gpt2_slobench"
-    if not (path / "manifest.json").exists():
-        api = build_model(cfg)
-        partition_and_save(api.init(jax.random.PRNGKey(0)), cfg, path)
-    return path
+    return ensure_partitioned(
+        CKPT_ROOT / "gpt2_slobench", cfg,
+        lambda: build_model(cfg).init(jax.random.PRNGKey(0)))
 
 
 def _trace(vocab):

@@ -35,7 +35,7 @@ import time
 import jax
 import numpy as np
 
-from repro.checkpoint import load_manifest, partition_and_save
+from repro.checkpoint import ensure_partitioned, load_manifest
 from repro.configs import get_config
 from repro.core import PipeloadEngine
 from repro.core.engine import SpecConfig
@@ -57,11 +57,9 @@ def _cfg():
 
 
 def _ckpt(cfg):
-    path = CKPT_ROOT / "gpt2_specbench"
-    if not (path / "manifest.json").exists():
-        api = build_model(cfg)
-        partition_and_save(api.init(jax.random.PRNGKey(0)), cfg, path)
-    return path
+    return ensure_partitioned(
+        CKPT_ROOT / "gpt2_specbench", cfg,
+        lambda: build_model(cfg).init(jax.random.PRNGKey(0)))
 
 
 def _gen(ckpt, cfg, prompt, budget, spec):

@@ -8,8 +8,8 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro.checkpoint import (ensure_quantized, load_manifest,
-                              partition_and_save)
+from repro.checkpoint import (ensure_partitioned, ensure_quantized,
+                              load_manifest)
 from repro.configs import get_config, list_paper_models
 from repro.models.api import build_model
 
@@ -50,12 +50,9 @@ def paper_cfg(name: str):
 
 def ensure_paper_ckpt(name: str, quant: str | None = None) -> Path:
     cfg, _ = paper_cfg(name)
-    path = CKPT_ROOT / name
-    if not (path / "manifest.json").exists():
-        api = build_model(cfg)
-        params = api.init(jax.random.PRNGKey(0))
-        partition_and_save(params, cfg, path)
-        del params
+    path = ensure_partitioned(
+        CKPT_ROOT / name, cfg,
+        lambda: build_model(cfg).init(jax.random.PRNGKey(0)))
     if quant:
         return ensure_quantized(path, CKPT_ROOT / f"{name}-{quant}", quant)
     return path

@@ -11,7 +11,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax
 
-from repro.checkpoint import partition_and_save
+from repro.checkpoint import ensure_partitioned
 from repro.configs import get_config
 from repro.core import Hermes
 from repro.models.api import build_model
@@ -31,9 +31,8 @@ def main():
     if args.reduced:
         cfg = cfg.reduced().with_(num_layers=8)
     ckpt = Path(f"/tmp/repro_plan_{cfg.name.replace('.', '_')}")
-    if not (ckpt / "manifest.json").exists():
-        api = build_model(cfg)
-        partition_and_save(api.init(jax.random.PRNGKey(0)), cfg, ckpt)
+    ensure_partitioned(ckpt, cfg,
+                       lambda: build_model(cfg).init(jax.random.PRNGKey(0)))
 
     h = Hermes(ckpt, cfg)
     prof = h.profile()

@@ -25,7 +25,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 import numpy as np
 
-from repro.checkpoint import partition_and_save
+from repro.checkpoint import ensure_partitioned
 from repro.configs import get_config
 from repro.core import BatchScheduler, Hermes, PipeloadEngine
 from repro.models.api import build_model
@@ -47,10 +47,8 @@ def main():
 
     cfg = get_config("gpt2_base")
     ckpt = Path("/tmp/repro_example_gpt2")
-    if not (ckpt / "manifest.json").exists():
-        print("building + partitioning gpt2-base checkpoint (one-off)...")
-        api = build_model(cfg)
-        partition_and_save(api.init(jax.random.PRNGKey(0)), cfg, ckpt)
+    ensure_partitioned(ckpt, cfg,
+                       lambda: build_model(cfg).init(jax.random.PRNGKey(0)))
 
     toks = np.random.default_rng(0).integers(0, cfg.vocab_size, (1, 4))
     budget = int(args.budget_mb * 2**20)

@@ -3,14 +3,24 @@
 A model checkpoint is pre-processed into per-layer shards on disk:
 
     <dir>/manifest.json
-    <dir>/embed.npz          # embedding ("other layers" in the paper)
-    <dir>/layer_000.npz ...  # encoder/decoder layers (the 70-95% bulk)
-    <dir>/head.npz           # final norm + lm/classifier head
+    <dir>/embed.bin          # embedding ("other layers" in the paper)
+    <dir>/layer_000.bin ...  # encoder/decoder layers (the 70-95% bulk)
+    <dir>/head.bin           # final norm + lm/classifier head
 
-Each shard is an .npz of named arrays; the manifest records byte sizes,
-kinds and per-shard dtype/scale metadata so the Pipeline Planner can
-reason about the schedule without opening shards.  Loading a shard is a
-real disk read (np.load with regular I/O).
+Each shard is one raw file: its tensors laid end to end, each at an
+offset aligned to 64 bytes.  The shard's manifest row carries its
+tensor table (dotted key, dtype by name, shape, offset, nbytes; a small
+non-array value such as a quantized tensor's original dtype is a
+``value`` entry of the table and takes no file bytes) and ``file_bytes``,
+the file's size.  The manifest also records byte sizes, kinds and
+per-shard dtype/scale metadata so the Pipeline Planner can reason about
+the schedule without opening shards; its ``format`` names this layout
+(``SHARD_FORMAT``).  Loading a shard is one real disk read: one
+``readinto`` on an unbuffered file fills one fresh 64-byte-aligned host
+buffer, and the arrays handed back are views into it — no parse, no
+checksum, no copy per tensor.  A directory whose manifest names another
+format is stale: ``load_manifest`` / ``load_shard`` refuse it, and
+``ensure_partitioned`` / ``ensure_quantized`` write it anew.
 
 ``quant="int8" | "int4"`` writes per-channel-scaled integer shards
 (``checkpoint/quant.py``): 2-D matmul weights are stored as integer
@@ -37,9 +47,10 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
+import ml_dtypes  # noqa: F401  (numpy learns dtype names such as bfloat16)
 import numpy as np
 
 from repro.checkpoint import quant as qz
@@ -48,6 +59,20 @@ from repro.models.config import DENSE, MOE, VLM, ModelConfig
 # Families whose param trees use the dense layout this partitioner (and
 # the engine's module fns) understand.
 PARTITION_FAMILIES = (DENSE, MOE, VLM)
+
+# the manifest's ``format``: raw shard files, tensors at 64-byte offsets
+SHARD_FORMAT = "raw-align64-v1"
+ALIGN = 64
+
+
+class StaleCheckpointError(ValueError):
+    """A checkpoint directory whose manifest names no current format."""
+
+
+def _stale(path) -> StaleCheckpointError:
+    return StaleCheckpointError(
+        f"checkpoint {path} is not in the {SHARD_FORMAT!r} shard format "
+        f"(an older store, such as .npz shards): partition it again")
 
 
 def _flatten(tree, prefix="") -> Dict[str, np.ndarray]:
@@ -71,13 +96,38 @@ def _unflatten(flat: Dict[str, np.ndarray]) -> dict:
     return tree
 
 
+def _write_shard(file: Path, stored: Dict[str, object]
+                 ) -> Tuple[List[dict], int]:
+    """Lay ``stored``'s arrays end to end in ``file``, each at a
+    64-byte-aligned offset; a string goes into the table as a ``value``.
+    Returns the tensor table and the file's size."""
+    table: List[dict] = []
+    offset = 0
+    with open(file, "wb") as f:
+        for key, val in stored.items():
+            if isinstance(val, str):
+                table.append({"key": key, "value": str(val)})
+                continue
+            a = np.asarray(val)
+            shape = list(a.shape)
+            a = np.ascontiguousarray(a).reshape(-1)
+            pad = -offset % ALIGN
+            f.write(bytes(pad))
+            offset += pad
+            f.write(a.view(np.uint8).data)
+            table.append({"key": key, "dtype": a.dtype.name, "shape": shape,
+                          "offset": offset, "nbytes": int(a.nbytes)})
+            offset += int(a.nbytes)
+    return table, offset
+
+
 def _save_shard(path: Path, name: str, flat: Dict[str, np.ndarray],
                 kind: str, index: int, quant: Optional[str],
                 base_dtype: str, extra: Optional[dict] = None) -> dict:
     """Write one (possibly quantized) shard and return its manifest row."""
     fp_bytes = int(sum(a.nbytes for a in flat.values()))
     stored = qz.quantize_flat(flat, quant)
-    np.savez(path / f"{name}.npz", **stored)
+    table, file_bytes = _write_shard(path / f"{name}.bin", stored)
     nbytes = int(sum(np.asarray(a).nbytes for a in stored.values()))
     row = {"name": name, "kind": kind, "index": index, "bytes": nbytes,
            "dtype": quant or base_dtype}
@@ -89,6 +139,8 @@ def _save_shard(path: Path, name: str, flat: Dict[str, np.ndarray],
             np.asarray(a).nbytes for k, a in stored.items()
             if k.endswith(".__scale__")))
         row["n_quantized"] = sum(1 for k in stored if k.endswith(".__q__"))
+    row["file_bytes"] = file_bytes
+    row["tensors"] = table
     return row
 
 
@@ -158,6 +210,7 @@ def _build_manifest(model: str, num_layers: int, dtype: str,
                     shards: List[dict], quant: Optional[str], *,
                     expert_split: bool = False) -> dict:
     manifest = {
+        "format": SHARD_FORMAT,
         "model": model,
         "num_layers": num_layers,
         "dtype": dtype,
@@ -195,8 +248,7 @@ def requantize(src, dst, quant: str) -> dict:
     dst.mkdir(parents=True, exist_ok=True)
     shards = []
     for s in src_man["shards"]:
-        with np.load(src / f"{s['name']}.npz") as z:
-            flat = {k: z[k] for k in z.files}
+        flat = _read_flat(src, s)
         extra = {"expert": s["expert"]} if "expert" in s else None
         shards.append(_save_shard(dst, s["name"], flat, s["kind"],
                                   s["index"], quant, src_man["dtype"],
@@ -213,33 +265,105 @@ def requantize(src, dst, quant: str) -> dict:
 def ensure_quantized(src, dst, quant: str) -> Path:
     """Transcode ``src`` into quantized shards at ``dst`` unless a
     CURRENT transcode already sits there.  "Current" means the existing
-    manifest carries the right ``quant`` tag and the source fingerprint
-    (its manifest byte total) — without the check, re-partitioning the
-    source in place would leave derived int8/int4 shards silently
-    serving the *old* weights."""
+    manifest is in this store's format and carries the right ``quant``
+    tag and the source fingerprint (its manifest byte total) — without
+    the check, re-partitioning the source in place would leave derived
+    int8/int4 shards silently serving the *old* weights."""
     src, dst = Path(src), Path(dst)
-    if (dst / "manifest.json").exists():
+    if is_current(dst):
         dst_man = load_manifest(dst)
         src_man = load_manifest(src)
         if (dst_man.get("quant") == quant
                 and dst_man.get("source_total_bytes")
                 == src_man["total_bytes"]):
             return dst
+    _drop_old_shards(dst)
     requantize(src, dst, quant)
     return dst
 
 
+def is_current(path) -> bool:
+    """True when ``path`` holds a manifest in this store's format."""
+    file = Path(path) / "manifest.json"
+    return (file.exists()
+            and json.loads(file.read_text()).get("format") == SHARD_FORMAT)
+
+
+def ensure_partitioned(path, cfg: ModelConfig,
+                       make_params: Callable[[], dict], **kw) -> Path:
+    """The checkpoint directory every caller that keeps one across runs
+    goes through: ``path`` as it is when it holds a checkpoint in this
+    store's format, else ``make_params()`` partitioned into it anew
+    (``kw`` as ``partition_and_save`` takes them), the old store's
+    shard files removed."""
+    path = Path(path)
+    if not is_current(path):
+        _drop_old_shards(path)
+        partition_and_save(make_params(), cfg, path, **kw)
+    return path
+
+
+def _drop_old_shards(path: Path) -> None:
+    for f in path.glob("*.npz"):
+        f.unlink()
+
+
 def load_manifest(path) -> dict:
-    return json.loads((Path(path) / "manifest.json").read_text())
+    """The manifest of ``path``; raises ``StaleCheckpointError`` for a
+    directory written in another shard format."""
+    manifest = json.loads((Path(path) / "manifest.json").read_text())
+    if manifest.get("format") != SHARD_FORMAT:
+        raise _stale(path)
+    return manifest
 
 
 def shard_names(manifest: dict) -> List[str]:
     return [s["name"] for s in manifest["shards"]]
 
 
-def load_shard(path, name: str) -> dict:
-    """Real disk read -> nested dict of np arrays (quantized entries come
-    back as QuantizedTensor leaves)."""
-    with np.load(Path(path) / f"{name}.npz") as z:
-        flat = {k: z[k] for k in z.files}   # forces the read
-    return qz.restore_tree(_unflatten(flat))
+def _aligned_empty(nbytes: int) -> np.ndarray:
+    """A fresh uint8 host buffer of ``nbytes`` at a 64-byte-aligned
+    address."""
+    raw = np.empty(nbytes + ALIGN, np.uint8)
+    start = -raw.ctypes.data % ALIGN
+    return raw[start:start + nbytes]
+
+
+def _read_flat(path, row: dict) -> Dict[str, object]:
+    """One shard's flat ``{dotted key: array | value}``: one raw read
+    into one fresh aligned buffer, the arrays read-only views into it.
+    Fresh per shard, never reused: the device copy of the last shard's
+    views may still be reading its buffer."""
+    path = Path(path)
+    if "tensors" not in row:
+        raise _stale(path)
+    buf = _aligned_empty(int(row["file_bytes"]))
+    view = memoryview(buf)
+    with open(path / f"{row['name']}.bin", "rb", buffering=0) as f:
+        done = 0
+        while done < len(buf):
+            n = f.readinto(view[done:])
+            if not n:
+                raise EOFError(f"{f.name}: read {done} of {len(buf)} bytes")
+            done += n
+    buf.flags.writeable = False
+    flat: Dict[str, object] = {}
+    for t in row["tensors"]:
+        if "value" in t:
+            flat[t["key"]] = t["value"]
+            continue
+        lo = t["offset"]
+        flat[t["key"]] = (buf[lo:lo + t["nbytes"]]
+                          .view(np.dtype(t["dtype"])).reshape(t["shape"]))
+    return flat
+
+
+def load_shard(path, name: str, row: Optional[dict] = None) -> dict:
+    """Real disk read -> nested dict of np arrays, views into one host
+    buffer (quantized entries come back as QuantizedTensor leaves).
+    ``row`` is the shard's manifest row; without it the manifest is
+    read."""
+    if row is None:
+        row = next(s for s in load_manifest(path)["shards"]
+                   if s["name"] == name)
+    return qz.restore_tree(_unflatten(_read_flat(path, row)))

@@ -21,11 +21,13 @@ int8/int4 shards:
     so the fp copy of at most the layer being computed is transient and
     never resident between rounds.
 
-``quantize_flat`` / ``restore_tree`` are the npz serialisation halves
-used by ``checkpoint/partition.py``: a quantized array at flat key ``k``
-is stored as ``k.__q__`` / ``k.__scale__`` / ``k.__meta__`` /
-``k.__dtype__`` so the existing dotted-key unflattening nests them into
-a dict that ``restore_tree`` folds back into a ``QuantizedTensor``.
+``quantize_flat`` / ``restore_tree`` are the serialisation halves used
+by ``checkpoint/partition.py``'s raw shard store: a quantized array at
+flat key ``k`` is stored as ``k.__q__`` / ``k.__scale__`` /
+``k.__meta__`` arrays in the shard file and a ``k.__dtype__`` string in
+the shard's tensor table, so the existing dotted-key unflattening nests
+them into a dict that ``restore_tree`` folds back into a
+``QuantizedTensor``.
 """
 from __future__ import annotations
 
@@ -172,12 +174,12 @@ def dequant_tree(tree):
 
 
 # ---------------------------------------------------------------------------
-# npz (de)serialisation of flat {dotted_key: array} shard dicts
+# (de)serialisation of flat {dotted_key: array} shard dicts
 # ---------------------------------------------------------------------------
 def quantize_flat(flat: Dict[str, np.ndarray],
                   quant: Optional[str]) -> Dict[str, np.ndarray]:
     """Replace every quantizable array in a flat shard dict with its
-    ``__q__/__scale__/__meta__/__dtype__`` quadruple."""
+    ``__q__/__scale__/__meta__`` arrays and ``__dtype__`` string."""
     if quant is None:
         return dict(flat)
     out: Dict[str, np.ndarray] = {}
@@ -195,7 +197,7 @@ def quantize_flat(flat: Dict[str, np.ndarray],
 
 def restore_tree(tree):
     """Fold ``{__q__, __scale__, __meta__, __dtype__}`` dicts (produced
-    by unflattening a quantized npz) back into QuantizedTensor leaves."""
+    by unflattening a quantized shard) back into QuantizedTensor leaves."""
     if not isinstance(tree, dict):
         return tree
     if _Q in tree:

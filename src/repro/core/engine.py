@@ -422,8 +422,9 @@ class DraftModel:
             ledger.acquire(self.total_bytes, owner="draft")
         if self.weights is None:
             self.weights = {
-                name: jax.tree.map(jnp.asarray, load_shard(self.dir, name))
-                for name in self.shards}
+                name: jax.tree.map(jnp.asarray,
+                                   load_shard(self.dir, name, row))
+                for name, row in self.shards.items()}
         return self
 
     def unpin(self, ledger: Optional[_Ledger] = None):
@@ -501,6 +502,10 @@ class PipeloadEngine:
         self.shards = {s["name"]: s for s in self.manifest["shards"]}
         self.layer_names = [s["name"] for s in self.manifest["shards"]
                             if s["kind"] == "layer"]
+        # every shard read (always on; the read rate is
+        # shard_read_bytes / shard_read_s)
+        self._read_bytes = _tele.metrics().counter("loader.shard_read_bytes")
+        self._read_s = _tele.metrics().counter("loader.shard_read_s")
         # persistent across pipeline rounds (pinning / non-destroying modes)
         self._resident: Dict[str, dict] = {}
         # the prefetch stream of the round in progress (telemetry only)
@@ -560,20 +565,30 @@ class PipeloadEngine:
     # ------------------------------------------------------------------
     def _load(self, name: str) -> dict:
         """Disk -> host -> device ("memory" tier).  Traced as
-        ``shard_read`` (the parse into host arrays) and ``shard_put``
-        (issuing the host-to-device copies, which may still be in
-        flight when it ends), with the round's stream number when a
-        round's Loading Agent makes the load."""
+        ``shard_read`` (the raw read into one host buffer) and
+        ``shard_put`` (issuing the host-to-device copies, which may
+        still be in flight when it ends), with the round's stream number
+        when a round's Loading Agent makes the load.  Traced or not, the
+        read's manifest bytes and seconds add to the ``loader.*``
+        counters."""
+        row = self.shards[name]
         tr = _tele.get_tracer()
         if not tr.enabled:
-            return jax.tree.map(jnp.asarray, load_shard(self.dir, name))
-        args = {"key": name, "bytes": int(self.shards[name]["bytes"])}
+            return jax.tree.map(jnp.asarray, self._read(row))
+        args = {"key": name, "bytes": int(row["bytes"])}
         if self._stream_id is not None:
             args["stream"] = self._stream_id
         with tr.span("shard_read", **args):
-            host = load_shard(self.dir, name)
+            host = self._read(row)
         with tr.span("shard_put", **args):
             return jax.tree.map(jnp.asarray, host)
+
+    def _read(self, row: dict) -> dict:
+        t0 = time.perf_counter()
+        host = load_shard(self.dir, row["name"], row)
+        self._read_s.inc(time.perf_counter() - t0)
+        self._read_bytes.inc(int(row["bytes"]))
+        return host
 
     @contextlib.contextmanager
     def _round(self):

@@ -301,7 +301,7 @@ class ExpertStreamEngine:
     def _load_one(self, row: dict) -> dict:
         name = row["name"]
         t = time.perf_counter() - self._t0
-        host = load_shard(self.dir, name)
+        host = load_shard(self.dir, name, row)
         w = jax.tree.map(jnp.asarray, host)
         self._events.append((t, "load_start", name))
         self._event("load_end", name)

@@ -24,7 +24,7 @@ from __future__ import annotations
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence
 
-from repro.checkpoint.partition import ensure_quantized
+from repro.checkpoint.partition import ensure_quantized, load_manifest
 from repro.core.engine import DraftModel, PipeloadEngine, RunStats
 from repro.core.planner import GenPlanEntry, PlanEntry, plan, plan_generate
 from repro.core.profiler import load_profile, profile_model, save_profile
@@ -56,13 +56,17 @@ class Hermes:
                 force: bool = False) -> Dict:
         """Measured per-shard profile, cached in the checkpoint directory
         under the measuring device's kind (``profile.<arch>.json``): a
-        profile taken on one device never plans a run on another."""
+        profile taken on one device never plans a run on another.  A
+        cached profile whose shard ``format`` is not the manifest's was
+        measured on another store's reads, and is measured again."""
         cache = self.dir / f"profile.{device_arch()}.json"
         if not force and self._profile is not None:
             return self._profile
         if not force and cache.exists():
-            self._profile = load_profile(cache)
-            return self._profile
+            kept = load_profile(cache)
+            if kept.get("format") == load_manifest(self.dir)["format"]:
+                self._profile = kept
+                return self._profile
         self._profile = profile_model(self.dir, self.cfg, batch=batch,
                                       seq=seq)
         save_profile(self._profile, cache)

@@ -11,7 +11,9 @@ shards ARE smaller on disk and the module fns DO pay the in-jit dequant
 measurements, not scaled estimates.  The profile carries the manifest's
 ``quant``/``dtype`` tags so the Pipeline Planner can search shard dtype
 jointly with the schedule (pass one profile per quantized variant as
-``{dtype: profile}``).
+``{dtype: profile}``).  It also carries the manifest's shard ``format``:
+``t_load`` is a property of how shards are stored and read, so
+``Hermes.profile()`` measures again when a kept profile's format differs.
 
 Expert-split MoE checkpoints additionally get per-expert byte/latency
 rows: every kind-``expert`` shard lands in the profile with its manifest
@@ -43,13 +45,15 @@ from repro.core.prefetch import PrefetchRuntime
 from repro.models.config import ModelConfig
 
 
-def _timed_device_load(runtime: PrefetchRuntime, ckpt_dir, name: str):
+def _timed_device_load(runtime: PrefetchRuntime, ckpt_dir, row: dict):
     """One disk -> host -> device shard load, timed on the shared
     prefetch runtime (the same pool the Loading Agents use, so
     ``t_load`` measures the path serving actually takes)."""
+    name = row["name"]
+
     def _load():
         with _tele.get_tracer().span("profile_load", shard=name):
-            w = jax.tree.map(jnp.asarray, load_shard(ckpt_dir, name))
+            w = jax.tree.map(jnp.asarray, load_shard(ckpt_dir, name, row))
             jax.tree.map(lambda a: a.block_until_ready(), w)
         return w
     return runtime.timed_load(_load)
@@ -88,6 +92,7 @@ def _profile_model(ckpt_dir, cfg: ModelConfig, manifest, fns, tokens,
                    expert_sample: int, batch: int, seq: int) -> Dict:
     expert_split = bool(manifest.get("expert_split"))
     profile = {"model": cfg.name, "batch": batch, "seq": seq,
+               "format": manifest["format"],
                "quant": manifest.get("quant"),
                "ckpt_dtype": manifest.get("dtype", cfg.dtype),
                "expert_split": expert_split, "shards": []}
@@ -105,7 +110,7 @@ def _profile_model(ckpt_dir, cfg: ModelConfig, manifest, fns, tokens,
             if shard["expert"] < expert_sample:
                 t_loads = []
                 for _ in range(repeats):
-                    w, dt = _timed_device_load(runtime, ckpt_dir, name)
+                    w, dt = _timed_device_load(runtime, ckpt_dir, shard)
                     t_loads.append(dt)
                 row["t_load"] = float(np.median(t_loads))
                 expert_t_loads.append(row["t_load"])
@@ -115,7 +120,7 @@ def _profile_model(ckpt_dir, cfg: ModelConfig, manifest, fns, tokens,
         # ---- load time (disk -> device), cold-ish: re-read every repeat
         t_loads = []
         for _ in range(repeats):
-            w, dt = _timed_device_load(runtime, ckpt_dir, name)
+            w, dt = _timed_device_load(runtime, ckpt_dir, shard)
             t_loads.append(dt)
         # ---- compute time (expert-split MoE layers run the streamed
         # router -> fetch -> combine path; the warmup call loads the

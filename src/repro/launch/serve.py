@@ -85,7 +85,7 @@ from repro import compile_cache
 from repro.analysis.report import (drift_report, format_drift,
                                    format_peak_breakdown,
                                    peak_breakdown_report)
-from repro.checkpoint import partition_and_save
+from repro.checkpoint import ensure_partitioned
 from repro.configs import get, names
 from repro.core import SLO, BatchScheduler, Hermes
 from repro.core import telemetry as tele
@@ -111,10 +111,8 @@ def init_params(cfg, seed: int = 0):
 
 
 def ensure_checkpoint(cfg, seed: int = 0) -> Path:
-    path = CKPT_ROOT / cfg.name.replace("/", "_")
-    if not (path / "manifest.json").exists():
-        partition_and_save(init_params(cfg, seed), cfg, path)
-    return path
+    return ensure_partitioned(CKPT_ROOT / cfg.name.replace("/", "_"), cfg,
+                              lambda: init_params(cfg, seed))
 
 
 def poisson_arrivals(n: int, rate: float | None,

@@ -124,24 +124,31 @@ def test_hermes_planner_end_to_end(gpt2s, toks):
 def test_profile_cache_keyed_by_device(tmp_path, monkeypatch):
     """A profile cached by one device never plans a run on another: the
     checkpoint directory keeps one ``profile.<device kind>.json`` each."""
+    import json
+
+    from repro.checkpoint import SHARD_FORMAT
     from repro.core import hermes as hermes_mod
     from repro.core.profiler import save_profile
 
     cfg = get_config("gpt2_base").reduced()
-    save_profile({"measured_on": "cpu"}, tmp_path / "profile.cpu.json")
+    (tmp_path / "manifest.json").write_text(
+        json.dumps({"format": SHARD_FORMAT, "shards": []}))
+    on_cpu = {"measured_on": "cpu", "format": SHARD_FORMAT}
+    on_tpu = {"measured_on": "tpu-v5-lite", "format": SHARD_FORMAT}
+    save_profile(on_cpu, tmp_path / "profile.cpu.json")
     monkeypatch.setattr(hermes_mod, "device_arch", lambda: "cpu")
-    assert Hermes(tmp_path, cfg).profile() == {"measured_on": "cpu"}
+    assert Hermes(tmp_path, cfg).profile() == on_cpu
 
     runs = []
 
     def measure(*args, **kw):
         runs.append(args)
-        return {"measured_on": "tpu-v5-lite"}
+        return on_tpu
 
     monkeypatch.setattr(hermes_mod, "profile_model", measure)
     monkeypatch.setattr(hermes_mod, "device_arch", lambda: "tpu-v5-lite")
-    assert Hermes(tmp_path, cfg).profile() == {"measured_on": "tpu-v5-lite"}
+    assert Hermes(tmp_path, cfg).profile() == on_tpu
     assert len(runs) == 1                  # measured, not loaded
     assert (tmp_path / "profile.tpu-v5-lite.json").exists()
     monkeypatch.setattr(hermes_mod, "device_arch", lambda: "cpu")
-    assert Hermes(tmp_path, cfg).profile() == {"measured_on": "cpu"}
+    assert Hermes(tmp_path, cfg).profile() == on_cpu
